@@ -3,7 +3,6 @@
 Bridges the ``core/`` control plane (ordering, aggregation, replication
 *decisions*) to real JAX execution on a device mesh:
 
-* ``compat``      — one place absorbing jax-version API drift
 * ``sharding``    — partition policy: params / inputs / caches / activations
 * ``policy``      — the ``sharding_policy`` context + ``constrain`` hook
   the model forward passes call
@@ -16,21 +15,19 @@ Bridges the ``core/`` control plane (ordering, aggregation, replication
 * ``elastic``     — mesh rebuild + replica restore on device loss
 """
 
-from . import collectives, compat, elastic, flatbuf, policy, sharding
+from . import collectives, elastic, flatbuf, policy, sharding
 from .collectives import loss_drop_mask, mlfabric_grad_reduce, plan_buckets
 from .flatbuf import (ErrorFeedback, FlatLayout, SparseChunk, pack_leaves,
                       plan_flat_layout, sparse_quantize, topk_sparsify)
-from .compat import AxisType, make_mesh, shard_map
 from .elastic import ElasticSession, surviving_mesh
 from .policy import (PhaseLossCallback, PhaseLossPolicy, constrain,
                      sharding_policy)
 
 __all__ = [
-    "collectives", "compat", "elastic", "flatbuf", "policy", "sharding",
+    "collectives", "elastic", "flatbuf", "policy", "sharding",
     "loss_drop_mask", "mlfabric_grad_reduce", "plan_buckets",
     "ErrorFeedback", "FlatLayout", "SparseChunk", "pack_leaves",
     "plan_flat_layout", "sparse_quantize", "topk_sparsify",
-    "AxisType", "make_mesh", "shard_map",
     "ElasticSession", "surviving_mesh",
     "PhaseLossCallback", "PhaseLossPolicy", "constrain", "sharding_policy",
 ]

@@ -30,7 +30,10 @@ exist, while the next chunk's backprop runs.
 
 The functions must be called inside a ``shard_map`` body where
 ``intra_axis`` (and ``inter_axis``, when given) are manual mesh axes —
-see ``launch/steps.py:build_mlfabric_train_step``.
+see ``launch/steps.py:build_mlfabric_train_step``.  Mesh axes that body
+leaves automatic (``model``) are made manual around each Pallas kernel
+call (``_whole_bucket``): a compiled Mosaic kernel cannot be partitioned
+by GSPMD, so every device runs it on the whole bucket.
 """
 
 from __future__ import annotations
@@ -40,6 +43,7 @@ from typing import Any, Callable, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, PartitionSpec as P
 
 from ..kernels import (dequant_aggregate_op, grad_aggregate_op, quantize_op,
                        scatter_aggregate_op, switch_sum_op)
@@ -56,10 +60,30 @@ __all__ = ["Bucket", "plan_buckets", "loss_drop_mask", "mlfabric_grad_reduce",
 
 BACKENDS = ("host", "switch", "hierarchical")
 
+_WIRE_LANES = 1024   # int8 wire row: four 256-element quantization blocks
+
 
 # --------------------------------------------------------------------------- #
 # the aggregation hierarchy
 # --------------------------------------------------------------------------- #
+def _whole_bucket(op: Callable, *args):
+    """Call the kernel op ``op`` with every mesh axis manual.
+
+    Inside the step's ``shard_map`` the non-batch axes stay automatic, and
+    GSPMD cannot partition a compiled Mosaic kernel.  The remaining axes
+    become manual here with the operands replicated over them, so each
+    device aggregates the whole bucket (a no-op for size-1 axes).
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = frozenset(name for name, kind in
+                     zip(mesh.axis_names, mesh.axis_types)
+                     if kind != AxisType.Manual)
+    if not auto:
+        return op(*args)
+    return jax.shard_map(op, mesh=mesh, in_specs=P(), out_specs=P(),
+                         axis_names=auto, check_vma=False)(*args)
+
+
 def _intra_pod_switch_sum(vec: jax.Array, intra_axis: str, *,
                           window: int = 256) -> jax.Array:
     """Intra-pod stage in switch mode: fixed-point in-network aggregation.
@@ -82,7 +106,8 @@ def _intra_pod_switch_sum(vec: jax.Array, intra_axis: str, *,
     if pad:
         q = jnp.pad(q, (0, pad))
     qs = jax.lax.all_gather(q, intra_axis)       # [W, D_pad] int8 wire
-    s = switch_sum_op(qs, window=window, orig_len=d)
+    s = _whole_bucket(
+        lambda x: switch_sum_op(x, window=window, orig_len=d), qs)
     return s.astype(jnp.float32) * scale
 
 
@@ -108,6 +133,12 @@ def loss_drop_mask(loss: Any, src: str, dst: str, t: float,
     return mask
 
 
+def _quantize_wire(vec: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """int8 wire rows [D/1024, 1024] and scales [1, D/block] of ``vec``."""
+    q, s = quantize_op(vec)
+    return q.reshape(-1, _WIRE_LANES), s[None]
+
+
 def _inter_pod_aggregate(vec: jax.Array, inter_axis: str, *,
                          compress: bool) -> jax.Array:
     """Cross-pod stage: gather every pod's partial aggregate and run the
@@ -120,16 +151,26 @@ def _inter_pod_aggregate(vec: jax.Array, inter_axis: str, *,
     """
     if compress:
         d = vec.shape[0]
-        q, s = quantize_op(vec)                      # pads internally
-        qs = jax.lax.all_gather(q, inter_axis)       # [P, D_pad] int8 wire
-        ss = jax.lax.all_gather(s, inter_axis)       # [P, D_pad/block] f32
+        # The int8 wire travels as 1024-lane rows: XLA's TPU compiler takes
+        # time proportional to the payload (minutes per bucket) to gather
+        # int8 as [1, D] or as [D/256, 256] rows, and none for [D/1024,
+        # 1024].  So pad to whole rows of four quantization blocks.
+        pad = -d % _WIRE_LANES
+        if pad:
+            vec = jnp.pad(vec, (0, pad))
+        q, s = _whole_bucket(_quantize_wire, vec)
+        qs = jax.lax.all_gather(q, inter_axis)       # [P, D_pad/1024, 1024]
         n_pods = qs.shape[0]
-        agg, _ = dequant_aggregate_op(
-            qs, ss, jnp.ones((n_pods,), jnp.float32), orig_len=d)
+        qs = qs.reshape(n_pods, -1)                  # [P, D_pad] int8 wire
+        ss = jax.lax.all_gather(s, inter_axis, tiled=True)  # [P, D_pad/block]
+        agg, _ = _whole_bucket(
+            lambda q_, s_, w_: dequant_aggregate_op(q_, s_, w_, orig_len=d),
+            qs, ss, jnp.ones((n_pods,), jnp.float32))
         return agg
     gathered = jax.lax.all_gather(vec, inter_axis)   # [P, D] f32 wire
     n_pods = gathered.shape[0]
-    agg, _ = grad_aggregate_op(gathered, jnp.ones((n_pods,), jnp.float32))
+    agg, _ = _whole_bucket(grad_aggregate_op, gathered,
+                           jnp.ones((n_pods,), jnp.float32))
     return agg
 
 
@@ -166,8 +207,9 @@ def _inter_pod_aggregate_sparse(vec: jax.Array, inter_axis: str, *,
     qs = jax.lax.all_gather(q, inter_axis)           # [P, K] int8 wire
     ss = jax.lax.all_gather(scale, inter_axis)       # [P] f32
     n_pods = qs.shape[0]
-    agg, _ = scatter_aggregate_op(
-        idxs, qs, ss, jnp.ones((n_pods,), jnp.float32), d_out=d)
+    agg, _ = _whole_bucket(
+        lambda i_, q_, s_, w_: scatter_aggregate_op(i_, q_, s_, w_, d_out=d),
+        idxs, qs, ss, jnp.ones((n_pods,), jnp.float32))
     return agg
 
 

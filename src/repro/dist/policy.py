@@ -73,10 +73,9 @@ def constrain(x: jax.Array, name: str) -> jax.Array:
     """Apply the active policy's constraint for ``name`` (identity if no
     policy is bound or the policy has no entry for ``name``).
 
-    Inside a ``shard_map`` body the constraint may reference axes the body
-    is manual over (old-jax limitation); that raises at trace time, and we
-    fall back to the unconstrained value — the spec is a layout hint, never
-    a semantics change.
+    Inside a ``shard_map`` body the policy must name only the axes the
+    body leaves automatic (``build_mlfabric_train_step`` binds such a
+    policy); a spec naming a manual axis raises at trace time.
     """
     pol = current_policy()
     if pol is None:
@@ -88,11 +87,7 @@ def constrain(x: jax.Array, name: str) -> jax.Array:
     fitted = _fit_spec(mesh, spec, x.shape)
     if all(e is None for e in fitted):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, fitted))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, fitted))
 
 
 # --------------------------------------------------------------------------- #

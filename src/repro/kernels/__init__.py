@@ -12,7 +12,8 @@
                            (the SwitchML-style in-network aggregation mode)
 
 Each has: the kernel (pl.pallas_call + BlockSpec), a jit wrapper in
-``ops.py`` (interpret-mode on CPU), and a pure-jnp oracle in ``ref.py``.
+``ops.py`` (compiled on TPU, interpreted on CPU, refused elsewhere — see
+``ops.interpret_mode``), and a pure-jnp oracle in ``ref.py``.
 """
 
 from .ops import (compress_update, dequant_aggregate_op, dequantize_op,

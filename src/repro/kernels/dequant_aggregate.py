@@ -23,7 +23,13 @@ double-buffered DMA staging — large buckets and wide fan-ins stream
 instead of assert-failing on VMEM.  Both trailing blocks may be ragged:
 out-of-bounds rows are masked via the weight vector, out-of-bounds columns
 are masked in the norm accumulation (OOB output writes are dropped by the
-pipeline itself).
+pipeline itself).  The squared norm accumulates into one SMEM scalar that
+stays resident across the whole (sequential) grid.
+
+TPU tiling: a scales tile holds ``block_d // block`` scales on the lane
+axis, so a D tile narrower than the bucket must span a multiple of 128
+quantization blocks — the default ``block_d`` is exactly 128 blocks of
+256.  A bucket that fits in one tile is one full-array block instead.
 """
 
 from __future__ import annotations
@@ -34,6 +40,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _fused_kernel(q_ref, s_ref, w_ref, out_ref, ssq_ref, *, block: int,
@@ -55,7 +62,12 @@ def _fused_kernel(q_ref, s_ref, w_ref, out_ref, ssq_ref, *, block: int,
     deq = (q.astype(jnp.float32).reshape(chunk_n, block_d // block, block)
            * s[:, :, None].astype(jnp.float32)).reshape(chunk_n, block_d)
     deq = jnp.where(live, deq, 0.0)
-    partial = jnp.sum(deq * w, axis=0)         # [block_d]
+    out_len = out_ref.shape[0]                 # block_d, or d_out if 1 tile
+    partial = jnp.sum(deq * w, axis=0)[:out_len]
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        ssq_ref[0] = 0.0
 
     @pl.when(j == 0)
     def _():
@@ -69,14 +81,14 @@ def _fused_kernel(q_ref, s_ref, w_ref, out_ref, ssq_ref, *, block: int,
     def _():
         # ragged D tile: columns >= d_out must not pollute the norm (their
         # output writes are dropped, but the VMEM tile still holds them)
-        col = (jax.lax.broadcasted_iota(jnp.int32, (1, block_d), 1)
-               .reshape(block_d) + i * block_d)
+        col = (jax.lax.broadcasted_iota(jnp.int32, (1, out_len), 1)
+               .reshape(out_len) + i * block_d)
         agg = out_ref[...]
-        ssq_ref[0] = jnp.sum(jnp.where(col < d_out, jnp.square(agg), 0.0))
+        ssq_ref[0] += jnp.sum(jnp.where(col < d_out, jnp.square(agg), 0.0))
 
 
 def dequant_aggregate(q: jax.Array, scales: jax.Array, weights: jax.Array, *,
-                      block: int = 256, block_d: int = 2048,
+                      block: int = 256, block_d: int = 32768,
                       chunk_n: int = 8, orig_len: int | None = None,
                       interpret: bool = False
                       ) -> Tuple[jax.Array, jax.Array]:
@@ -97,6 +109,7 @@ def dequant_aggregate(q: jax.Array, scales: jax.Array, weights: jax.Array, *,
     block_d = max(block_d - block_d % block, block)  # whole quant blocks
     chunk_n = min(chunk_n, n)
     grid = (pl.cdiv(d_out, block_d), pl.cdiv(n, chunk_n))
+    out_block = d_out if grid[0] == 1 else block_d
 
     kernel = functools.partial(_fused_kernel, block=block, block_d=block_d,
                                chunk_n=chunk_n, n_total=n, d_out=d_out)
@@ -109,13 +122,15 @@ def dequant_aggregate(q: jax.Array, scales: jax.Array, weights: jax.Array, *,
             pl.BlockSpec((chunk_n, 1), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_d,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
+            pl.BlockSpec((out_block,), lambda i, j: (i,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((d_out,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
+            jax.ShapeDtypeStruct((1,), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(q, scales, weights[:, None])
-    return agg, jnp.sum(ssq)
+    return agg, ssq[0]

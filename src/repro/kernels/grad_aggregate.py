@@ -10,8 +10,8 @@ N + write 1 + read 1).
 
 Tiling: grid over ceil(D/block_d) column tiles; each step stages an
 [N, block_d] tile of the stacked updates into VMEM, reduces over N on the
-VPU, writes the aggregated tile and accumulates the tile's sum-of-squares
-into an SMEM scalar emitted per-tile (summed by the jit wrapper).  A
+VPU, writes the aggregated tile and adds the tile's sum-of-squares into
+one SMEM scalar that stays resident across the (sequential) grid.  A
 ragged last tile is masked in-kernel (out-of-bounds lanes are excluded
 from the norm; their output writes are dropped by the pipeline), so
 callers never pay a pad-to-block copy + slice over the full gradient.
@@ -39,7 +39,12 @@ def _agg_kernel(u_ref, w_ref, out_ref, ssq_ref, *, block_d: int, d: int):
     # norm stays exact (their aggregated writes are dropped anyway)
     agg = jnp.sum(jnp.where(valid[None, :], u, 0.0) * w, axis=0)
     out_ref[...] = agg.astype(out_ref.dtype)
-    ssq_ref[0] = jnp.sum(jnp.where(valid, jnp.square(agg), 0.0))
+
+    @pl.when(i == 0)
+    def _():
+        ssq_ref[0] = 0.0
+
+    ssq_ref[0] += jnp.sum(jnp.where(valid, jnp.square(agg), 0.0))
 
 
 def grad_aggregate(updates: jax.Array, weights: jax.Array, *,
@@ -48,6 +53,8 @@ def grad_aggregate(updates: jax.Array, weights: jax.Array, *,
     """updates: [N, D]; weights: [N] -> (agg [D] same dtype, sumsq [] f32).
 
     Any D works: the last tile is masked in-kernel, not padded in HBM.
+    On TPU a ``block_d`` below D must be a multiple of 1024 (the HBM tile
+    of a 1-D f32 array).
     """
     n, d = updates.shape
     block_d = min(block_d, d)
@@ -63,12 +70,14 @@ def grad_aggregate(updates: jax.Array, weights: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((block_d,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((d,), updates.dtype),
-            jax.ShapeDtypeStruct((n_blocks,), jnp.float32),
+            jax.ShapeDtypeStruct((1,), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(updates, weights[:, None])
-    return agg, jnp.sum(ssq)
+    return agg, ssq[0]

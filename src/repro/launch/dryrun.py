@@ -103,8 +103,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax <= 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll_total, coll_kinds = collective_bytes(hlo)
 

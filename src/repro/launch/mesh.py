@@ -7,8 +7,7 @@ never touches jax device state — required by the dry-run contract.
 from __future__ import annotations
 
 import jax
-
-from ..dist.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,15 +19,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over the real local devices (smoke tests / examples)."""
     n = len(jax.devices())
     data = min(data, n)
-    return make_mesh((data, max(n // data, 1)), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((data, max(n // data, 1)), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def batch_axes(mesh) -> tuple:

@@ -16,7 +16,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from ..configs.shapes import ShapeConfig
-from ..dist import compat
 from ..dist import sharding as shd
 from ..dist.policy import sharding_policy
 from ..models import api as model_api
@@ -234,9 +233,9 @@ def build_mlfabric_train_step(cfg: ModelConfig, shape: ShapeConfig,
     out_specs = (spec_of(abstract_params, rep), spec_of(abstract_opt, rep),
                  {"loss": P(), "aux_loss": P(), "grad_norm": P()})
 
-    step = compat.shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, axis_names=set(batch_axes),
-                            check_vma=False)
+    step = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(batch_axes),
+                         check_vma=False)
 
     # model-axis shardings for the jit boundary (params sharded over model,
     # replicated over batch axes)

@@ -27,6 +27,7 @@ from ..models import build_model
 from ..optim import momentum_sgd_init, momentum_sgd_update, wsd_schedule, \
     cosine_schedule
 from ..optim.sgd import update_norm
+from .compile_cache import use_compile_cache
 
 
 def main(argv=None) -> int:
@@ -47,6 +48,7 @@ def main(argv=None) -> int:
                     default="cosine")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

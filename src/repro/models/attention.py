@@ -29,8 +29,8 @@ from .layers import Params, apply_rope, dense_init
 NEG_INF = -1e30
 
 # attention implementation: "blockwise" (pure-jnp online softmax; the
-# GSPMD/dry-run path) or "pallas" (the TPU flash kernel in repro/kernels —
-# selected by the TPU launcher; interpret-mode on CPU).
+# GSPMD/dry-run path) or "pallas" (the flash kernel in repro/kernels, run
+# as ``kernels.ops.interpret_mode`` decides).
 _ATTN_IMPL = "blockwise"
 
 
@@ -82,12 +82,13 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if (_ATTN_IMPL == "pallas" and dk == dv and q_offset == 0 and sq == skv
             and sq % 16 == 0):
         from ..kernels.flash_attention import flash_attention
+        from ..kernels.ops import interpret_mode
         out = flash_attention(q.transpose(0, 2, 1, 3),
                               k.transpose(0, 2, 1, 3),
                               v.transpose(0, 2, 1, 3), causal=causal,
                               scale=scale,
                               block_q=min(128, sq), block_k=min(128, skv),
-                              interpret=jax.default_backend() != "tpu")
+                              interpret=interpret_mode())
         return out.transpose(0, 2, 1, 3)
 
     if skv <= kv_block:  # small sequences: one block, no scan

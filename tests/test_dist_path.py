@@ -51,13 +51,13 @@ _MULTIDEV_SCRIPT = textwrap.dedent("""
     import sys; sys.path.insert(0, "src")
     import dataclasses, jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config, get_shape
-    from repro.dist.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.launch.steps import build_step
     from repro.optim.sgd import momentum_sgd_init
     from repro.models import transformer as tf
 
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("stablelm-1.6b").reduced()
     shape = dataclasses.replace(get_shape("train_4k"), seq_len=128,
                                 global_batch=4)
@@ -109,10 +109,11 @@ _COLLECTIVES_SCRIPT = textwrap.dedent("""
     import sys; sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType
     from repro.dist.collectives import mlfabric_grad_reduce
-    from repro.dist.compat import make_mesh, shard_map
 
-    mesh = make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     # one gradient slice per device on the leading dim (2 pods x 4 workers)
     grads = {
@@ -130,8 +131,8 @@ _COLLECTIVES_SCRIPT = textwrap.dedent("""
                                         inter_axis="pod", mean_over=8, **kw)
         specs = jax.tree.map(lambda _: P(("pod", "data")), grads)
         outs = jax.tree.map(lambda _: P(), grads)
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=(specs,),
-                              out_specs=outs, check_vma=False))
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                  out_specs=outs, check_vma=False))
         return jax.device_get(f(grads))
 
     cases = {
@@ -163,6 +164,53 @@ def test_mlfabric_grad_reduce_matches_psum_mean():
                          capture_output=True, text=True, timeout=600,
                          cwd=_REPO_ROOT)
     assert "COLLECTIVES_NUMERICS_OK" in res.stdout, res.stderr[-2000:]
+
+
+_PODS_F32_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys; sys.path.insert(0, "src")
+    import dataclasses, jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType
+    from repro.configs import get_config, get_shape
+    from repro.data import SyntheticLM
+    from repro.launch.steps import build_step
+    from repro.models import transformer as tf
+    from repro.optim.sgd import momentum_sgd_init
+
+    mesh = jax.make_mesh((2, 2, 1), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
+    cfg = get_config("qwen2-0.5b").reduced()
+    shape = dataclasses.replace(get_shape("train_4k"), seq_len=64,
+                                global_batch=4)
+    params = tf.init_params(jax.random.key(0), cfg, dtype=jnp.float32)
+    opt = momentum_sgd_init(params)
+    batch = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=64,
+                        seed=0).batch(0, 4)
+    outs = {}
+    for path in ("auto", "mlfabric"):
+        b = build_step(cfg, shape, mesh, lr=0.1, grad_path=path)
+        p2, o2, m = jax.jit(b.fn)(params, opt, batch)
+        outs[path] = (float(m["loss"]), jax.device_get((p2, o2.history)))
+    assert abs(outs["auto"][0] - outs["mlfabric"][0]) < 1e-5, outs
+    for a, b_ in zip(jax.tree.leaves(outs["auto"][1]),
+                     jax.tree.leaves(outs["mlfabric"][1])):
+        np.testing.assert_allclose(a, b_, rtol=1e-5, atol=1e-6)
+    print("PODS_F32_OK")
+""")
+
+
+@pytest.mark.slow
+def test_mlfabric_step_matches_auto_across_pods_f32():
+    """With f32 parameters the MLfabric step on a (pod=2, data=2, model=1)
+    mesh (kernels called with every remaining axis manual) gives GSPMD's
+    parameters and gradient to f32 rounding: what the bf16 comparison in
+    ``chip_smoke.py --chips 4`` sees beyond that is rounding, not a lost or
+    misplaced member."""
+    res = subprocess.run([sys.executable, "-c", _PODS_F32_SCRIPT],
+                         capture_output=True, text=True, timeout=600,
+                         cwd=_REPO_ROOT)
+    assert "PODS_F32_OK" in res.stdout, res.stderr[-2000:]
 
 
 def test_gradient_accumulation_matches_full_batch():

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
 from repro.dist.collectives import plan_buckets
 from repro.dist.elastic import surviving_mesh
 from repro.dist.policy import constrain, current_policy, sharding_policy
@@ -111,14 +110,14 @@ class TestMeshHelpers:
             surviving_mesh([], data=1, model=1)
 
     def test_compat_shard_map_psum(self):
-        mesh = compat.make_mesh((1, len(jax.devices())), ("data", "model"))
+        mesh = make_host_mesh(data=1)
 
         def body(x):
             return jax.lax.psum(x, "model")
 
-        f = compat.shard_map(body, mesh=mesh, in_specs=P("model"),
-                             out_specs=P("model"),
-                             axis_names={"data", "model"}, check_vma=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=P("model"),
+                          out_specs=P("model"),
+                          axis_names={"data", "model"}, check_vma=False)
         n = len(jax.devices())
         out = f(jnp.ones((n,)))
         np.testing.assert_array_equal(np.asarray(out), np.full((n,), n))

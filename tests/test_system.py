@@ -6,6 +6,8 @@ delay bounds), bounded-divergence replication, checkpoint/restart — loss
 must go down, invariants must hold.
 """
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,9 +35,9 @@ def test_end_to_end_async_lm_training():
     model = build_model(cfg)
     src = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, seed=0)
 
-    def data_fn(worker, t):
-        return {k: jnp.asarray(v)
-                for k, v in src.batch(hash(worker) % 997 + t, 4).items()}
+    def data_fn(worker, t):   # crc32, not hash(): str hashes vary per run
+        cursor = zlib.crc32(worker.encode()) % 997 + t
+        return {k: jnp.asarray(v) for k, v in src.batch(cursor, 4).items()}
 
     eval_batch = {k: jnp.asarray(v) for k, v in src.batch(12345, 8).items()}
 
