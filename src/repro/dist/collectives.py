@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, PartitionSpec as P
 
+from .. import scopes
 from ..kernels import (dequant_aggregate_op, grad_aggregate_op, quantize_op,
                        scatter_aggregate_op, switch_sum_op)
 # Re-exported for backwards compatibility: the bucket planner grew into the
@@ -231,8 +232,7 @@ def reduce_flat_buckets(grads: Params, layout: FlatLayout, *,
                         backend: str = "host",
                         drop_mask_inter: Optional[
                             Union[Callable[[int], Any], Any]] = None,
-                        token: Optional[jax.Array] = None,
-                        tracer: Any = None
+                        token: Optional[jax.Array] = None
                         ) -> Tuple[List[jax.Array], jax.Array]:
     """Pack ``grads`` flat and reduce every bucket in issue order.
 
@@ -254,62 +254,48 @@ def reduce_flat_buckets(grads: Params, layout: FlatLayout, *,
     ``functools.partial(loss_drop_mask, loss, src, dst, t)``) since the
     top-k slot count varies per bucket.
 
-    ``tracer`` (a ``repro.obs.trace.Tracer``) gets one ``bucket`` span per
-    issued bucket.  This function usually runs under ``jit``, so the span
-    clock is *issue* (trace-construction) wall-clock, not device time —
-    what it shows is the planned SJF issue order and per-bucket payload,
-    which is exactly the schedule MLfabric reasons about.
+    The work is named on the device (``repro.scopes``): the pack under
+    ``pack``, each bucket under ``bucket<kk>`` in issue order, with its
+    intra-pod sum under ``intra`` and its inter-pod stage under ``inter``.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")
     if backend == "hierarchical":
         compress_inter = True
     leaves = jax.tree_util.tree_leaves(grads)
-    flat = pack_leaves(leaves)                       # single fused scatter
+    with jax.named_scope(scopes.PACK):
+        flat = pack_leaves(leaves)                   # single fused scatter
     if token is None:
         token = jnp.zeros((), jnp.float32)
-    if tracer is not None:
-        import time as _time
-        t0 = _time.perf_counter()
     reduced: List[jax.Array] = []
     for k in range(len(layout.buckets)):
-        if tracer is not None:
-            t_issue = _time.perf_counter() - t0
-        vec = bucket_slice(flat, layout, k)          # zero-copy view
-        # Chain each bucket on the previous one's result: the compiler
-        # must issue the collectives in the planned (SJF) order.
-        vec, token = jax.lax.optimization_barrier((vec, token))
-        if backend == "host":
-            vec = jax.lax.psum(vec, intra_axis)      # intra-pod reduce
-        else:
-            vec = _intra_pod_switch_sum(vec, intra_axis)
-        if inter_axis is not None:
-            if keep_inter is not None:
-                d_bkt = vec.shape[0]
-                k_top = max(1, min(d_bkt, int(round(keep_inter * d_bkt))))
-                mask = (drop_mask_inter(k_top) if callable(drop_mask_inter)
-                        else drop_mask_inter)
-                vec = _inter_pod_aggregate_sparse(vec, inter_axis,
-                                                  keep=keep_inter,
-                                                  drop_mask=mask)
-            else:
-                vec = _inter_pod_aggregate(vec, inter_axis,
-                                           compress=compress_inter)
-        vec = vec / mean_over
-        token = vec[0] * 0.0
+        with jax.named_scope(scopes.bucket(k)):
+            vec = bucket_slice(flat, layout, k)      # zero-copy view
+            # Chain each bucket on the previous one's result: the compiler
+            # must issue the collectives in the planned (SJF) order.
+            vec, token = jax.lax.optimization_barrier((vec, token))
+            with jax.named_scope(scopes.INTRA):
+                if backend == "host":
+                    vec = jax.lax.psum(vec, intra_axis)  # intra-pod reduce
+                else:
+                    vec = _intra_pod_switch_sum(vec, intra_axis)
+            if inter_axis is not None:
+                with jax.named_scope(scopes.INTER):
+                    if keep_inter is not None:
+                        d_bkt = vec.shape[0]
+                        k_top = max(1, min(d_bkt,
+                                           int(round(keep_inter * d_bkt))))
+                        mask = (drop_mask_inter(k_top)
+                                if callable(drop_mask_inter)
+                                else drop_mask_inter)
+                        vec = _inter_pod_aggregate_sparse(
+                            vec, inter_axis, keep=keep_inter, drop_mask=mask)
+                    else:
+                        vec = _inter_pod_aggregate(vec, inter_axis,
+                                                   compress=compress_inter)
+            vec = vec / mean_over
+            token = vec[0] * 0.0
         reduced.append(vec)
-        if tracer is not None:
-            b = layout.buckets[k]
-            tracer.span(f"bucket{k} ({len(b.indices)} leaves)", cat="bucket",
-                        track=intra_axis, ts=t_issue,
-                        dur=_time.perf_counter() - t0 - t_issue,
-                        args={"bucket": k, "bytes": b.nbytes,
-                              "leaves": list(b.indices),
-                              "inter": inter_axis or "",
-                              "backend": backend,
-                              "compressed": bool(compress_inter),
-                              "keep": keep_inter if keep_inter is not None
-                              else 1.0})
     return reduced, token
 
 
@@ -319,9 +305,10 @@ def unpack_reduced(reduced: List[jax.Array], layout: FlatLayout,
     (zero-copy sub-slices of each bucket)."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     out: List[Optional[jax.Array]] = [None] * len(leaves)
-    for k, vec in enumerate(reduced):
-        for i, leaf in unpack_bucket(vec, layout, k, leaves):
-            out[i] = leaf
+    with jax.named_scope(scopes.UNPACK):
+        for k, vec in enumerate(reduced):
+            for i, leaf in unpack_bucket(vec, layout, k, leaves):
+                out[i] = leaf
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -334,7 +321,7 @@ def mlfabric_grad_reduce(grads: Params, *, intra_axis: str = "data",
                          backend: str = "host",
                          drop_mask_inter: Optional[
                              Union[Callable[[int], Any], Any]] = None,
-                         mean_over: int = 1, tracer: Any = None) -> Params:
+                         mean_over: int = 1) -> Params:
     """Scheduled hierarchical mean of a gradient pytree.
 
     Numerically equivalent (to f32 reduction tolerance; int8 tolerance
@@ -353,9 +340,10 @@ def mlfabric_grad_reduce(grads: Params, *, intra_axis: str = "data",
         return grads
     layout = plan_reduce(grads, bucket_bytes=bucket_bytes,
                          shortest_first=shortest_first)
-    reduced, _ = reduce_flat_buckets(
-        grads, layout, intra_axis=intra_axis, inter_axis=inter_axis,
-        compress_inter=compress_inter, keep_inter=keep_inter,
-        backend=backend, drop_mask_inter=drop_mask_inter,
-        mean_over=mean_over, tracer=tracer)
-    return unpack_reduced(reduced, layout, grads)
+    with jax.named_scope(scopes.EXCHANGE):
+        reduced, _ = reduce_flat_buckets(
+            grads, layout, intra_axis=intra_axis, inter_axis=inter_axis,
+            compress_inter=compress_inter, keep_inter=keep_inter,
+            backend=backend, drop_mask_inter=drop_mask_inter,
+            mean_over=mean_over)
+        return unpack_reduced(reduced, layout, grads)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import scopes
 from ..configs.base import ModelConfig
 from ..configs.shapes import ShapeConfig
 from ..dist import sharding as shd
@@ -70,8 +71,8 @@ def build_train_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
     def train_step(params, opt_state, batch):
         with sharding_policy(mesh, act):
             def scalar_loss(p, b):
-                total, metrics = tf.loss_fn(p, b, cfg=cfg, remat=remat)
-                return total, metrics
+                with jax.named_scope(scopes.MODEL):
+                    return tf.loss_fn(p, b, cfg=cfg, remat=remat)
 
             if microbatches == 1:
                 (_, metrics), grads = jax.value_and_grad(
@@ -99,11 +100,13 @@ def build_train_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
                 metrics = {"loss": loss_sum / microbatches,
                            "aux_loss": aux_sum / microbatches}
 
-            gnorm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads)))
-            new_params, new_opt = momentum_sgd_update(
-                params, grads, opt_state, lr=lr, gamma=gamma)
+            with jax.named_scope(scopes.METRICS):
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads)))
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_params, new_opt = momentum_sgd_update(
+                    params, grads, opt_state, lr=lr, gamma=gamma)
             out_metrics = {"loss": metrics["loss"],
                            "aux_loss": metrics["aux_loss"],
                            "grad_norm": gnorm}
@@ -179,13 +182,14 @@ def build_mlfabric_train_step(cfg: ModelConfig, shape: ShapeConfig,
         def chunk_grads(b):
             with sharding_policy(mesh, act):
                 def scalar_loss(p):
-                    total, metrics = tf.loss_fn(p, b, cfg=cfg, remat=remat)
-                    return total, metrics
+                    with jax.named_scope(scopes.MODEL):
+                        return tf.loss_fn(p, b, cfg=cfg, remat=remat)
                 return jax.value_and_grad(scalar_loss, has_aux=True)(params)
 
         if overlap_chunks == 1:
             (_, metrics), grads = chunk_grads(batch)
-            reduced, _ = reduce_flat_buckets(grads, layout, **reduce_kw)
+            with jax.named_scope(scopes.EXCHANGE):
+                reduced, _ = reduce_flat_buckets(grads, layout, **reduce_kw)
         else:
             chunks = {k: v.reshape(overlap_chunks,
                                    v.shape[0] // overlap_chunks,
@@ -199,20 +203,25 @@ def build_mlfabric_train_step(cfg: ModelConfig, shape: ShapeConfig,
                 # backward has no dependency on chunk c's collectives
                 (_, m), g = chunk_grads(
                     {k: v[c] for k, v in chunks.items()})
-                vecs, token = reduce_flat_buckets(g, layout, token=token,
-                                                  **reduce_kw)
-                reduced = [r + v for r, v in zip(reduced, vecs)]
+                with jax.named_scope(scopes.EXCHANGE):
+                    vecs, token = reduce_flat_buckets(g, layout, token=token,
+                                                      **reduce_kw)
+                    reduced = [r + v for r, v in zip(reduced, vecs)]
                 loss = loss + m["loss"]
                 aux = aux + m["aux_loss"]
-            reduced = [r / overlap_chunks for r in reduced]
+            with jax.named_scope(scopes.EXCHANGE):
+                reduced = [r / overlap_chunks for r in reduced]
             metrics = {"loss": loss / overlap_chunks,
                        "aux_loss": aux / overlap_chunks}
-        grads = unpack_reduced(reduced, layout, params)
-        new_params, new_opt = momentum_sgd_update(params, grads, opt_state,
-                                                  lr=lr, gamma=gamma)
-        loss = jax.lax.pmean(metrics["loss"], "data")
-        if inter:
-            loss = jax.lax.pmean(loss, inter)
+        with jax.named_scope(scopes.EXCHANGE):
+            grads = unpack_reduced(reduced, layout, params)
+        with jax.named_scope(scopes.OPTIMIZER):
+            new_params, new_opt = momentum_sgd_update(
+                params, grads, opt_state, lr=lr, gamma=gamma)
+        with jax.named_scope(scopes.METRICS):
+            loss = jax.lax.pmean(metrics["loss"], "data")
+            if inter:
+                loss = jax.lax.pmean(loss, inter)
         out_metrics = {"loss": loss, "aux_loss": metrics["aux_loss"],
                        "grad_norm": jnp.zeros((), jnp.float32)}
         return new_params, new_opt, out_metrics

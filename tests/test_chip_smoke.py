@@ -48,8 +48,10 @@ def test_kernels_interpret_only_on_cpu(monkeypatch, platform):
 @pytest.fixture
 def restore_cache_dir():
     was = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 def test_compile_cache_honours_env(monkeypatch, tmp_path, restore_cache_dir):
@@ -67,3 +69,17 @@ def test_compile_cache_in_checkout_from_any_cwd(monkeypatch, tmp_path,
         monkeypatch.chdir(cwd)
         assert compile_cache.use_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
+
+
+@pytest.mark.parametrize("env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_keys_on_metadata(monkeypatch, tmp_path,
+                                        restore_cache_dir, env):
+    # a cached step must carry the op_name scopes of the source that asked
+    # for it, not of whichever source compiled it first
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    compile_cache.use_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
