@@ -48,6 +48,7 @@ from jax.sharding import AxisType, PartitionSpec as P
 from .. import scopes
 from ..kernels import (dequant_aggregate_op, grad_aggregate_op, quantize_op,
                        scatter_aggregate_op, switch_sum_op)
+from ..kernels.quantize import WIRE_LANES
 # Re-exported for backwards compatibility: the bucket planner grew into the
 # flat-layout planner and moved to flatbuf.py.
 from .flatbuf import (Bucket, FlatLayout, bucket_slice, pack_leaves,
@@ -60,8 +61,6 @@ __all__ = ["Bucket", "plan_buckets", "loss_drop_mask", "mlfabric_grad_reduce",
            "plan_reduce", "reduce_flat_buckets", "unpack_reduced"]
 
 BACKENDS = ("host", "switch", "hierarchical")
-
-_WIRE_LANES = 1024   # int8 wire row: four 256-element quantization blocks
 
 
 # --------------------------------------------------------------------------- #
@@ -134,10 +133,18 @@ def loss_drop_mask(loss: Any, src: str, dst: str, t: float,
     return mask
 
 
+# The int8 wire has one layout from sender to receiver: rows of
+# WIRE_LANES = 1024 lanes, four 256-element quantization blocks each,
+# ``[D_pad/1024, 1024]``.  ``quantize_op`` writes those rows from the f32
+# bucket as it lies in HBM, the inter-pod all-gather stacks the pods' rows
+# as ``[P, D_pad/1024, 1024]``, and the receive kernel reads that array as
+# it is, one full (32, 128) int8 tile after another.  No step between them
+# moves the payload.
 def _quantize_wire(vec: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """int8 wire rows [D/1024, 1024] and scales [1, D/block] of ``vec``."""
+    """int8 wire rows [D/1024, 1024] and scales [1, D/block] of ``vec``
+    (the reshape only names the rows ``quantize_op`` wrote)."""
     q, s = quantize_op(vec)
-    return q.reshape(-1, _WIRE_LANES), s[None]
+    return q.reshape(-1, WIRE_LANES), s[None]
 
 
 def _inter_pod_aggregate(vec: jax.Array, inter_axis: str, *,
@@ -147,8 +154,9 @@ def _inter_pod_aggregate(vec: jax.Array, inter_axis: str, *,
 
     With ``compress`` the wire payload is the int8 blocks + f32 scales
     (the §8-complementary gradient compression); the receiving aggregator
-    host runs ONE fused dequantize+aggregate+norm pass over the stacked
-    payloads — never materializing per-pod f32 copies in HBM.
+    host runs ONE fused dequantize+aggregate+norm pass over the gathered
+    wire rows as the all-gather stacks them — never materializing per-pod
+    f32 copies, or a relaid copy of the int8 payload, in HBM.
     """
     if compress:
         d = vec.shape[0]
@@ -156,13 +164,12 @@ def _inter_pod_aggregate(vec: jax.Array, inter_axis: str, *,
         # time proportional to the payload (minutes per bucket) to gather
         # int8 as [1, D] or as [D/256, 256] rows, and none for [D/1024,
         # 1024].  So pad to whole rows of four quantization blocks.
-        pad = -d % _WIRE_LANES
+        pad = -d % WIRE_LANES
         if pad:
             vec = jnp.pad(vec, (0, pad))
         q, s = _whole_bucket(_quantize_wire, vec)
         qs = jax.lax.all_gather(q, inter_axis)       # [P, D_pad/1024, 1024]
         n_pods = qs.shape[0]
-        qs = qs.reshape(n_pods, -1)                  # [P, D_pad] int8 wire
         ss = jax.lax.all_gather(s, inter_axis, tiled=True)  # [P, D_pad/block]
         agg, _ = _whole_bucket(
             lambda q_, s_, w_: dequant_aggregate_op(q_, s_, w_, orig_len=d),

@@ -58,16 +58,17 @@ def grad_aggregate_op(updates, weights, *, block_d: int = 2048):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block", "block_d", "chunk_n",
+                   static_argnames=("block", "row_tile", "chunk_n",
                                     "orig_len"))
 def dequant_aggregate_op(q, scales, weights, *, block: int = 256,
-                         block_d: int = 32768, chunk_n: int = 8,
+                         row_tile: int = 512, chunk_n: int = 8,
                          orig_len: Optional[int] = None):
     """Fused aggregator receive path: int8 payloads -> dequantize ->
     weighted sum -> ||agg||^2 in one VMEM-resident pass (the unfused
     composition is ``vmap(dequantize_op)`` + ``grad_aggregate_op``, which
-    round-trips N dequantized f32 copies through HBM)."""
-    return _deq_agg(q, scales, weights, block=block, block_d=block_d,
+    round-trips N dequantized f32 copies through HBM).  ``q`` is the
+    gathered wire, [N, R, 1024], or [N, D] payloads."""
+    return _deq_agg(q, scales, weights, block=block, row_tile=row_tile,
                     chunk_n=chunk_n, orig_len=orig_len,
                     interpret=interpret_mode())
 

@@ -6,8 +6,8 @@ int8-quantized gradient chunks: each sender contributes ``(idx, q, scale)``
 values, and one per-chunk scale.  The aggregator must scatter-add every
 surviving chunk into the dense flat buffer.  Doing that with XLA
 ``.at[].add`` materializes one dense [D] buffer per sender; this kernel
-builds the aggregate in a single pass with the output tile VMEM-resident,
-mirroring ``dequant_aggregate.py``'s streaming layout.
+builds the aggregate in a single pass with the output tile VMEM-resident
+while the senders' chunks stream past it.
 
 Grid: ``(D tiles, K tiles)`` with the K axis minor, so each [block_d]
 output tile accumulates every sender's sparse chunks before moving on;

@@ -10,9 +10,9 @@ addition is exact — and the accumulator is **int32**, the
 overflow-widening a real switch pipeline applies per packet (int8 lanes
 would saturate at two members; int32 holds 2^24 members at full scale).
 
-Layout/streaming mirrors ``dequant_aggregate.py``: grid ``(D tiles,
-N chunks)`` with the member-chunk dimension minor, so each output tile
-stays VMEM-resident while int8 slabs stream through double-buffered DMA.
+Layout/streaming: grid ``(D tiles, N chunks)`` with the member-chunk
+dimension minor, so each output tile stays VMEM-resident while int8 slabs
+stream through double-buffered DMA.
 ``block_d`` is clamped to whole ``window``s — a D tile is an integer
 number of switch slots, the kernel-side image of slot-windowed streaming.
 Ragged N chunks are masked via an iota row filter (OOB rows read garbage);

@@ -145,6 +145,30 @@ class TestQuantize:
         assert (diff <= 1).all()
         assert diff.mean() < 0.01
 
+    @pytest.mark.parametrize("d,block,row_tile", [
+        (300 * 1024, 256, 256),        # two row tiles, the last ragged
+        (37 * 1024 + 512, 256, 512),   # not whole wire rows: padded, cut
+        (200 * 1024, 128, 128),        # eight scales a row, ragged tiles
+        (3 * 1024, 256, 512),          # fewer rows than one int8 tile
+    ])
+    def test_wire_rows_match_block_formula(self, d, block, row_tile):
+        """Bit for bit the per-block formula, blocks in flat order: scale =
+        max(max|x| / 127, 1e-30), q = clip(round(x / scale), -127, 127)."""
+        x = jax.random.normal(jax.random.key(9), (d,), jnp.float32) * 2.0
+        q, s = quantize(x, block=block, row_tile=row_tile, interpret=True)
+
+        @jax.jit
+        def formula(x):
+            xb = x.reshape(-1, block)
+            scale = jnp.maximum(jnp.max(jnp.abs(xb), axis=1) / 127.0, 1e-30)
+            q = jnp.clip(jnp.round(xb / scale[:, None]), -127, 127)
+            return q.astype(jnp.int8).reshape(-1), scale
+
+        q_expect, s_expect = formula(x)
+        assert q.shape == (d,) and s.shape == (d // block,)
+        np.testing.assert_array_equal(np.asarray(s), np.asarray(s_expect))
+        np.testing.assert_array_equal(np.asarray(q), np.asarray(q_expect))
+
     def test_compression_ratio(self):
         from repro.kernels.ops import compress_update
         x = jax.random.normal(jax.random.key(8), (8192,), jnp.float32)
